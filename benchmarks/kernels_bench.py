@@ -50,10 +50,12 @@ def run():
                  f"B{Bs}xS{Ss}xH{Hs}xP{P}"))
 
     import numpy as np
-    u = jnp.asarray(np.clip(np.random.default_rng(0).normal(
-        0.5, 0.3, (64, 512)), 0, 1), jnp.float32)
+    from repro.kernels.pattern_summary import row_targets
+    u_np = np.clip(np.random.default_rng(0).normal(0.5, 0.3, (64, 512)),
+                   0, 1).astype(np.float32)
+    u, target = jnp.asarray(u_np), jnp.asarray(row_targets(u_np))
     rows.append(("kernels/pattern_summary_interpret",
-                 _t(lambda u: ops.pattern_summary(u), u, reps=2),
+                 _t(lambda u: ops.pattern_summary(u, target), u, reps=2),
                  "64 events x 512 samples (interpret mode)"))
     return rows
 

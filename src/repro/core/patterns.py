@@ -26,6 +26,13 @@ from repro.core.events import Kind, WorkerProfile
 MASS_FRACTION = 0.8
 
 
+def mass_target(total: np.ndarray) -> np.ndarray:
+    """The region mass that makes a gap bound feasible in the batched
+    backends, from each row's float64 sum: ``MASS_FRACTION`` of it, less a
+    1e-9 slack so a region holding exactly that share qualifies."""
+    return MASS_FRACTION * total - 1e-9
+
+
 def critical_duration(u: np.ndarray, mass: float = MASS_FRACTION
                       ) -> Tuple[int, int]:
     """Algorithm 1: smallest max-zero-gap subinterval with >= mass of the

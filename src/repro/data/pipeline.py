@@ -10,6 +10,7 @@ restarts resume mid-epoch deterministically.
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -40,20 +41,38 @@ class SyntheticLM:
         self.cfg = cfg
         self.data = data
 
+    @functools.cached_property
+    def _powers(self):
+        """(A, G) of ``batch_at`` for k = 0 .. seq_len."""
+        S, V = self.data.seq_len, self.cfg.vocab_size
+        A = np.ones(S + 1, np.int64)
+        G = np.zeros(S + 1, np.int64)
+        for k in range(1, S + 1):
+            A[k] = A[k - 1] * 31 % V
+            G[k] = (G[k - 1] * 31 + 1) % V
+        return A, G
+
     def batch_at(self, step: int) -> Dict[str, np.ndarray]:
         d = self.data
         rng = np.random.default_rng(
             (d.seed, step, d.shard))
         B, S, V = d.batch, d.seq_len, self.cfg.vocab_size
-        # structured stream: tok[t+1] = (a*tok[t] + b) % V with noise
-        a = 31, 17
-        x = np.zeros((B, S + 1), np.int64)
-        x[:, 0] = rng.integers(0, V, B)
-        mult = rng.integers(1, 8, B)[:, None]
-        for t in range(S):
-            nxt = (x[:, t] * 31 + 17 * mult[:, 0]) % V
-            noise = rng.random(B) < 0.05
-            x[:, t + 1] = np.where(noise, rng.integers(0, V, B), nxt)
+        # structured stream: tok[t+1] = (31*tok[t] + 17*m) % V, restarted
+        # at a random token with probability 0.05 per position.  Built
+        # without a per-position loop (the loader must outpace a chip's
+        # step): t tokens after its last restart r, a row holds
+        # f^(t-r)(tok[r]) with f^k(x) = (A[k]*x + 17*m*G[k]) % V,
+        # A[k] = 31^k and G[k] = 1 + 31 + ... + 31^(k-1), all mod V
+        m = rng.integers(1, 8, B)[:, None]
+        restart = rng.random((B, S + 1)) < 0.05
+        restart[:, 0] = True
+        fresh = rng.integers(0, V, (B, S + 1))
+        A, G = self._powers
+        pos = np.arange(S + 1)
+        last = np.maximum.accumulate(np.where(restart, pos, 0), axis=1)
+        k = pos - last
+        x0 = np.take_along_axis(fresh, last, axis=1)
+        x = (A[k] * x0 + 17 * m * G[k]) % V
         out = {"tokens": x[:, :-1].astype(np.int32),
                "labels": x[:, 1:].astype(np.int32)}
         if self.cfg.frontend == "audio":

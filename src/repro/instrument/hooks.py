@@ -17,6 +17,10 @@ from repro.core.events import Kind
 from repro.core.service import DiagnosisResult, PerfTrackerService
 from repro.instrument.tracer import Tracer
 
+#: a window closes at its deadline only once it holds this many whole
+#: iterations, so slow iterations still give the diagnosis a pattern
+MIN_WINDOW_ITERS = 3
+
 
 @dataclass
 class PerfTrackerConfig:
@@ -40,6 +44,7 @@ class PerfTracker:
             summarize_backend=cfg.summarize_backend)
         self.tracer = Tracer(worker)
         self._window_deadline: Optional[float] = None
+        self._window_iters = 0
         self.last_trigger: Optional[Trigger] = None
         self.results: List[DiagnosisResult] = []
 
@@ -52,9 +57,12 @@ class PerfTracker:
             self.last_trigger = trig
             self.tracer.start_window()
             self._window_deadline = now + self.cfg.window_s
-        elif self._window_deadline is not None \
-                and now >= self._window_deadline:
-            self._finish_window()
+            self._window_iters = 0
+        elif self._window_deadline is not None:
+            self._window_iters += name == "optimizer.step"
+            if now >= self._window_deadline \
+                    and self._window_iters >= MIN_WINDOW_ITERS:
+                self._finish_window()
 
     def _finish_window(self):
         self._window_deadline = None

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container kernels run with ``interpret=True`` (the Pallas
-interpreter executes the kernel body for correctness); on TPU backends the
-same calls lower to Mosaic. ``auto_interpret()`` picks per-backend.
+Off a TPU the kernels run with ``interpret=True`` (the Pallas interpreter
+executes the kernel body for correctness); on a TPU backend the same calls
+lower to Mosaic.  ``auto_interpret()`` is the one rule that decides.
 """
 from __future__ import annotations
 
@@ -36,6 +36,6 @@ def ssd_scan(x, dt, A, Bm, Cm, chunk=128, interpret=None):
 
 
 @partial(jax.jit, static_argnames=("block_events", "interpret"))
-def pattern_summary(u, block_events=8, interpret=None):
+def pattern_summary(u, target, block_events=8, interpret=None):
     interpret = auto_interpret() if interpret is None else interpret
-    return _psum(u, block_events=block_events, interpret=interpret)
+    return _psum(u, target, block_events=block_events, interpret=interpret)

@@ -66,16 +66,15 @@ def ssd_oracle(x, dt, A, Bm, Cm):
 # -- pattern summary -----------------------------------------------------------
 
 def pattern_summary_oracle(u: np.ndarray) -> np.ndarray:
-    """Per-row (mean, std, frac_len) via the exact Algorithm-1 search
-    (repro.core.patterns.critical_duration)."""
+    """Per-row (mean, std, count) via the exact Algorithm-1 search
+    (repro.core.patterns.critical_duration); all-zero rows: (0, 0, n)."""
     out = []
     for row in np.asarray(u, np.float64):
         n = len(row)
         if row.sum() <= 0:
-            out.append((0.0, 0.0, 1.0))
+            out.append((0.0, 0.0, float(n)))
             continue
         lo, hi = critical_duration(row)
         seg = row[lo:hi]
-        out.append((float(seg.mean()), float(seg.std()),
-                    (hi - lo) / n))
+        out.append((float(seg.mean()), float(seg.std()), float(hi - lo)))
     return np.asarray(out, np.float32)

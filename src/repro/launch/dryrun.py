@@ -7,14 +7,9 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma2-2b \
       --shape train_4k --mesh single
 """
-# The very first two lines (before ANY other import): 512 placeholder host
-# devices so jax.make_mesh can build the production mesh.
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -189,6 +184,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
 
 
 def main():
+    # 512 placeholder host devices for the production mesh; set before the
+    # first device query (importing jax does not create a backend), so
+    # importing this module never reshapes devices
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                               + os.environ.get("XLA_FLAGS", ""))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

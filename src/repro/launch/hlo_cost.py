@@ -53,6 +53,26 @@ _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _WINDOW_SIZE_RE = re.compile(r"window=\{size=([0-9x]+)")
 _FEATURE_GROUPS_RE = re.compile(r"feature_group_count=(\d+)")
 
+#: per-chip roofline peaks by ``jax.Device.device_kind``:
+#: (dense bf16 FLOP/s, memory bytes/s)
+PEAKS: Dict[str, Tuple[float, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
+    "TPU v5 lite": (197e12, 819e9),
+    # the XLA CPU backend: nominal host rates, no published peak -- they
+    # only set the gemm/other split ratio of CPU runs
+    "cpu": (5e10, 2e10),
+}
+
+
+def peak_rates(device_kind: str) -> Tuple[float, float]:
+    """(FLOP/s, bytes/s) of one device of ``device_kind``; a kind missing
+    from ``PEAKS`` is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no roofline peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                   "collective-permute")
 

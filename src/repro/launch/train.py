@@ -3,24 +3,14 @@
   PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --reduced \
       --steps 100 --ckpt-dir /tmp/ckpt
 
-Production posture: ``--mesh single|multi`` builds the 256/512-chip mesh
-(placeholder host devices in this container; on real TPU pods the same code
-runs under jax.distributed with megascale DCN transport). XLA flags for
-compute/comm overlap (latency-hiding scheduler, async collectives) are set
-here for TPU targets.
+``--mesh DATAxMODEL`` (e.g. ``2x2`` on a four-chip host) shards the job
+over the devices jax reports, as they are.  The jax compile cache goes
+where ``JAX_COMPILATION_CACHE_DIR`` says, else to ``.jax_cache/`` at the
+checkout root (``repro.launch.cache``).
 """
 from __future__ import annotations
 
 import argparse
-import os
-
-TPU_XLA_FLAGS = " ".join([
-    # compute/comm overlap on TPU targets (no-ops on CPU)
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-    "--xla_tpu_enable_async_all_gather=true",
-    "--xla_enable_async_all_reduce=true",
-])
 
 
 def main():
@@ -36,8 +26,8 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--remat", default="none",
                     choices=["none", "dots", "full"])
-    ap.add_argument("--mesh", default="none",
-                    choices=["none", "single", "multi"])
+    ap.add_argument("--mesh", default="",
+                    help="DATAxMODEL over all local devices, e.g. 2x2")
     ap.add_argument("--grad-compress", default="none",
                     choices=["none", "bf16", "int8"])
     ap.add_argument("--no-perftracker", action="store_true")
@@ -46,15 +36,12 @@ def main():
                          "after step N/2 (reproduces case C2P1 online)")
     args = ap.parse_args()
 
-    if args.mesh != "none":
-        os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count"
-                                   "=512 " + os.environ.get("XLA_FLAGS", ""))
-
-    import jax
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs.registry import ARCHS, reduced
     from repro.data.pipeline import DataConfig
     from repro.dist.sharding import DistCtx
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import parse_mesh
     from repro.optim.adamw import OptConfig
     from repro.train.loop import TrainConfig, Trainer
 
@@ -63,9 +50,8 @@ def main():
         cfg = reduced(cfg)
 
     dist = None
-    if args.mesh != "none":
-        mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
-        dist = DistCtx.from_mesh(mesh)
+    if args.mesh:
+        dist = DistCtx.from_mesh(parse_mesh(args.mesh))
 
     data = DataConfig(batch=args.batch, seq_len=args.seq)
     tc = TrainConfig(steps=args.steps, ckpt_dir=args.ckpt_dir,

@@ -86,7 +86,9 @@ def init_mlp(key, d: int, ff: int, kind: str, use_bias: bool, stack=(),
     gated = kind in ("swiglu", "geglu")
     p = {}
     if gated:
-        p["wi"] = dense_init(k1, (d, 2, ff), stack, dtype)       # gate, up
+        # gate, up; fan-in d: drawn as one (d, 2*ff) matrix, then split
+        p["wi"] = dense_init(k1, (d, 2 * ff), stack, dtype).reshape(
+            stack + (d, 2, ff))
     else:
         p["wi"] = dense_init(k1, (d, ff), stack, dtype)
     p["wo"] = dense_init(k2, (ff, d), stack, dtype)

@@ -32,7 +32,9 @@ def init_moe(key, cfg, stack=(), dtype=jnp.float32):
     ks = jax.random.split(key, 4)
     p = {
         "router": L.dense_init(ks[0], (d, E), stack, jnp.float32),
-        "wi": L.dense_init(ks[1], (E, d, 2, ff), stack, dtype),
+        # fan-in d, as in L.init_mlp
+        "wi": L.dense_init(ks[1], (E, d, 2 * ff), stack, dtype).reshape(
+            stack + (E, d, 2, ff)),
         "wo": L.dense_init(ks[2], (E, ff, d), stack, dtype),
     }
     if cfg.num_shared_experts:
@@ -144,8 +146,7 @@ def apply_moe(p, x: Array, cfg, dist=None) -> Tuple[Array, Array]:
             stats = jax.lax.psum(stats, (tp,) + tuple(dp)) / ep
             return y.reshape(xl.shape), stats
 
-        from repro.dist.compat import shard_map
-        routed, stats = shard_map(
+        routed, stats = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P(dp, None, None)),
             out_specs=(P(dp, None, None), P()),

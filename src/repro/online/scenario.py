@@ -34,10 +34,16 @@ the identical detect -> summarize -> localize -> incident machinery over
 REAL jit'd training processes, whose measured iteration durations arrive
 as ``anchors`` wire frames and are merged (max per index) into the
 job-level detector stream.
+
+Both multiprocess paths keep the parent off jax: a chip belongs to one
+process, and every trainer child needs its own, so the trainer path refuses
+to start more children than the host has chips.
 """
 from __future__ import annotations
 
+import glob
 import multiprocessing as mp
+import os
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -57,6 +63,39 @@ from repro.online.workload import (SimWorkload, WorkloadSource,
 
 #: per-window profile seed offset (must match _mp_worker_main)
 _WINDOW_SEED_STRIDE = 7919
+
+
+#: where ``accelerator_chips`` looks (module constants so tests can point
+#: them at a fake tree)
+_PCI_DEVICES = "/sys/bus/pci/devices"
+_DEV = "/dev"
+
+
+def accelerator_chips() -> int:
+    """TPU chips this process could open, counted without starting a jax
+    backend (which would take a chip for itself), so a chip another
+    process holds counts all the same.  A chip is a TPU function on the
+    PCI bus (Google's vendor id and a TPU device id, as jax itself checks
+    before it loads the TPU runtime) whose device node this process sees:
+    ``/dev/vfio/<iommu group>`` when the chip is bound to vfio, else a
+    ``/dev/accel<N>`` node.  A host can hold more chips than it exposes to
+    one sandbox."""
+    from jax._src import hardware_utils as hw
+    tpus, groups = 0, []
+    for vendor in glob.glob(os.path.join(_PCI_DEVICES, "*", "vendor")):
+        dev = os.path.dirname(vendor)
+        with open(vendor) as f, open(os.path.join(dev, "device")) as g:
+            if f.read().strip() != hw._GOOGLE_PCI_VENDOR_ID \
+                    or g.read().strip() not in hw._TPU_PCI_DEVICE_IDS:
+                continue
+        tpus += 1
+        group = os.path.join(dev, "iommu_group")
+        if os.path.exists(group):
+            groups.append(os.path.basename(os.path.realpath(group)))
+    nodes = sum(os.path.exists(os.path.join(_DEV, "vfio", g))
+                for g in groups)
+    nodes += len(glob.glob(os.path.join(_DEV, "accel[0-9]*")))
+    return min(tpus, nodes)
 
 
 @dataclass(frozen=True)
@@ -457,12 +496,18 @@ class ScenarioRunner:
                              "name (str or None), got an instance")
         wl = self.workload
         W = wl.total_workers
+        n_procs = max(1, min(int(n_procs), W))
+        chips = accelerator_chips()
+        if chips and n_procs > chips:
+            raise RuntimeError(
+                f"{n_procs} trainer processes need a chip each, and this "
+                f"host has {chips}; pass n_procs <= {chips}, or run the "
+                f"workload in-process with run()")
         max_frame = max_frame_bytes(W)
         collector = WindowCollector(range(W))
         server = DaemonServer(collector, log_path=log_path,
                               auth_token=auth_token,
                               max_frame=max_frame).start()
-        n_procs = max(1, min(int(n_procs), W))
         slices = np.array_split(np.arange(W), n_procs)
         ctx = mp.get_context("spawn")
         procs = [

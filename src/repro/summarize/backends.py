@@ -8,16 +8,16 @@
               per sample).  Same selection rules as the Pallas kernel
               (max-mass feasible region, leftmost tie).
 ``pallas``  — the TPU kernel ``repro.kernels.pattern_summary`` wired into the
-              daemon pipeline; interpret mode off-TPU (see ENV_INTERPRET),
-              compiled on real hardware.
+              daemon pipeline; compiled on a TPU backend, interpret mode
+              everywhere else.
 """
 from __future__ import annotations
 
-import os
+from typing import Set, Tuple
 
 import numpy as np
 
-from repro.summarize.base import ENV_INTERPRET, register_backend
+from repro.summarize.base import register_backend
 
 
 class PythonBackend:
@@ -60,20 +60,12 @@ class NumpyBackend:
 
     name = "numpy"
 
-    def __init__(self, mass_fraction: float | None = None):
-        self.mass_fraction = mass_fraction
-
-    def _mass_fraction(self) -> float:
-        if self.mass_fraction is None:
-            # single source of truth; late import (patterns imports us back)
-            from repro.core.patterns import MASS_FRACTION
-            self.mass_fraction = MASS_FRACTION
-        return self.mass_fraction
-
     def available(self) -> bool:
         return True
 
     def batch_stats(self, u: np.ndarray) -> np.ndarray:
+        # late import: patterns imports the summarize package back
+        from repro.core.patterns import mass_target
         u = np.ascontiguousarray(u, np.float32)
         E, n = u.shape
         if E == 0 or n == 0:
@@ -85,7 +77,7 @@ class NumpyBackend:
         # under any zero-padding width), while sequential-f32 cumsum drifts
         # from it by enough to flip borderline feasibility on long rows
         total = u.sum(axis=1, dtype=np.float64)
-        target = self._mass_fraction() * total - 1e-9
+        target = mass_target(total)
         empty = total <= 0.0
         all_empty = np.stack([np.zeros(E), np.zeros(E),
                               np.full(E, float(n))], axis=1)
@@ -169,68 +161,84 @@ class NumpyBackend:
                                   (hi - lo).astype(np.float64)], axis=1))
 
 
+def _bucket(x: int, floor: int) -> int:
+    """Smallest power of two >= max(x, floor)."""
+    return 1 << (max(int(x), floor) - 1).bit_length()
+
+
 class PallasBackend:
-    """Batches rows through the TPU kernel; interpret mode everywhere else."""
+    """Batches rows through the TPU kernel; interpret mode everywhere else.
+
+    Every call pads its ``(E, n)`` matrix to power-of-two buckets (zero rows
+    and trailing zero samples change no row's result), so a run of many
+    profiling windows compiles a small, fixed set of kernel shapes;
+    ``shapes`` records the buckets this instance has run."""
 
     name = "pallas"
 
     def __init__(self, block_events: int = 8):
         self.block_events = block_events
+        self.shapes: Set[Tuple[int, int]] = set()
         self._jnp = None
 
     def _modules(self):
         if self._jnp is None:
             import jax.numpy as jnp
             from repro.kernels.ops import pattern_summary
+            from repro.kernels.pattern_summary import row_targets
             self._jnp = jnp
             self._kernel = pattern_summary
+            self._targets = row_targets
         return self._jnp, self._kernel
 
     def available(self) -> bool:
+        """Runnable wherever jax is installed.  The spec lookup lets a
+        jax-free process ask without paying the jax import; once jax is
+        there, a kernel that fails to load is an error, not a fallback."""
         if self._jnp is not None:
             return True
-        # spec lookup first: a jax-free process (no jax installed) must be
-        # able to ask 'is pallas available?' without paying the jax import
         import importlib.util
         if importlib.util.find_spec("jax") is None:
             return False
-        try:
-            self._modules()
-            return True
-        except Exception:
-            return False
+        self._modules()
+        return True
 
     def auto_ok(self) -> bool:
         """Only the ``auto`` default: compiled-on-TPU pallas is fast, the
-        interpreter is not — don't auto-pick it on CPU hosts.  Declines
-        without importing jax when nothing else has (a TPU training
-        process always has jax loaded; a CPU-only daemon may not, and
-        probing would cost it the whole jax import)."""
+        interpreter is not — don't auto-pick it on CPU hosts.  Asks only a
+        process whose jax backend is already up (a TPU training process
+        always is): probing would make a daemon or a fleet parent take the
+        chip away from the job it watches."""
         import sys
         if "jax" not in sys.modules:
             return False
-        if not self.available():
+        from jax._src import xla_bridge
+        if not xla_bridge.backends_are_initialized():
             return False
         import jax
         return jax.default_backend() == "tpu"
 
     def interpret(self) -> bool:
-        env = os.environ.get(ENV_INTERPRET)
-        if env is not None:
-            return env not in ("0", "false", "False")
-        import jax
-        return jax.default_backend() != "tpu"
+        from repro.kernels.ops import auto_interpret
+        return auto_interpret()
 
     def batch_stats(self, u: np.ndarray) -> np.ndarray:
-        jnp, kernel = self._modules()
         E, n = u.shape
-        out = np.asarray(kernel(jnp.asarray(u, jnp.float32),
+        if E == 0 or n == 0:
+            return np.zeros((E, 3))
+        jnp, kernel = self._modules()
+        shape = (_bucket(E, self.block_events), _bucket(n, 128))
+        padded = np.zeros(shape, np.float32)
+        padded[:E, :n] = u
+        target = np.zeros(shape[0], np.float32)   # padded rows: all zero
+        target[:E] = self._targets(padded[:E, :n])
+        self.shapes.add(shape)
+        out = np.asarray(kernel(jnp.asarray(padded), jnp.asarray(target),
                                 block_events=self.block_events,
-                                interpret=self.interpret()))
-        # kernel reports critical-duration *fraction* of the row width;
-        # the protocol wants sample counts
-        out = out.astype(np.float64)
-        out[:, 2] = np.rint(out[:, 2] * n)
+                                interpret=self.interpret()),
+                         np.float64)[:E]
+        # an all-zero row reports the padded width; no row is wider than n
+        out[:, 2] = np.minimum(out[:, 2], n)
         return out
 
 

@@ -15,10 +15,11 @@ with the row's true (unpadded) length, so backends need not know padding.
 Backends are registered by name and selected per call, per service, or
 globally via the ``REPRO_SUMMARIZE_BACKEND`` environment variable
 (``python`` | ``numpy`` | ``pallas`` | ``auto``).  ``auto`` (the default)
-prefers the fastest backend that can run in this process: ``pallas`` when a
-TPU is attached, else ``numpy``.  Unavailable backends fall back down the
-chain ``pallas -> numpy -> python`` rather than raising, so a fleet daemon
-never dies because its accelerator went away.
+prefers the fastest backend that can run in this process: ``pallas`` when
+this process already drives a TPU, else ``numpy``.  A backend that is not
+installed (``pallas`` without jax) falls back down the chain
+``pallas -> numpy -> python``; one that is installed but fails to load
+raises, so a broken kernel never hides behind the host path.
 """
 from __future__ import annotations
 
@@ -28,7 +29,6 @@ from typing import Callable, Dict, List, Optional, Protocol, runtime_checkable
 import numpy as np
 
 ENV_BACKEND = "REPRO_SUMMARIZE_BACKEND"
-ENV_INTERPRET = "REPRO_PALLAS_INTERPRET"
 
 #: fallback order used by ``auto`` and by unavailable explicit choices
 FALLBACK_CHAIN = ("pallas", "numpy", "python")
@@ -75,7 +75,7 @@ def _instance(name: str) -> SummarizeBackend:
 def get_backend(name: Optional[str] = None) -> SummarizeBackend:
     """Resolve a backend by explicit name, env var, or ``auto`` fallback.
 
-    An explicit/env choice that is registered but unavailable (e.g. ``pallas``
+    An explicit/env choice that is registered but not installed (``pallas``
     with no jax) degrades down FALLBACK_CHAIN instead of raising.
     """
     choice = name or os.environ.get(ENV_BACKEND, "auto")

@@ -34,13 +34,23 @@ from repro.instrument.hooks import PerfTracker, PerfTrackerConfig
 from repro.models.transformer import Transformer
 from repro.optim.adamw import AdamW, OptConfig
 from repro.train.step import make_split_train_step, make_train_step
+from repro.train.workload import default_trainer_detector_cfg
 
-#: CPU-ish roofline used to split the fused step's fenced span between the
-#: "xla.gemm" and "xla.other" cost-model sub-events (absolute values only
-#: set the split ratio; it is identical across same-program workers, so
-#: differential localization is insensitive to the constants)
-_ROOFLINE_FLOPS_S = 5e10
-_ROOFLINE_BYTES_S = 2e10
+
+def gemm_fraction(compiled) -> Optional[float]:
+    """Share of a compiled step the HLO cost model gives to matmuls: its
+    FLOPs and bytes against the roofline peaks of the device it runs on
+    (``hlo_cost.PEAKS``).  It sets where the fenced ``train.step`` span is
+    cut into ``xla.gemm`` / ``xla.other``; the ratio is identical across
+    same-program workers, so differential localization does not hinge on
+    the constants.  None when the module has no cost at all."""
+    from repro.launch.hlo_cost import expanded_cost, peak_rates
+    flops_s, bytes_s = peak_rates(jax.devices()[0].device_kind)
+    cost = expanded_cost(compiled.as_text(), num_devices=1)
+    t_gemm, t_other = cost.flops / flops_s, cost.bytes / bytes_s
+    if t_gemm + t_other <= 0.0:
+        return None
+    return min(0.95, max(0.05, t_gemm / (t_gemm + t_other)))
 
 
 @contextmanager
@@ -60,7 +70,7 @@ class StepBundle:
     exactly once."""
     grad_step: Callable
     opt_step: Callable
-    gemm_frac: Optional[float]      # None = HLO cost attribution unavailable
+    gemm_frac: Optional[float]      # None = the module has no cost
 
 
 @dataclass
@@ -91,8 +101,10 @@ class Trainer:
         self._jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
         self.pt: Optional[PerfTracker] = None
         if tc.perftracker:
+            # real iteration times are noisy: the thresholds made for them
             self.pt = PerfTracker(PerfTrackerConfig(
                 window_s=tc.pt_window_s,
+                detector=default_trainer_detector_cfg(6),
                 family="moe" if cfg.is_moe else "dense"))
             self._next, self._opt_anchor = self.pt.wrap(
                 self.loader.next, lambda: None)
@@ -153,21 +165,10 @@ class Trainer:
         if self.bundle is None:
             grad_fn, opt_fn = make_split_train_step(self.model, self.opt)
             compiled = jax.jit(grad_fn).lower(params, batch).compile()
-            gemm_frac = None
-            try:
-                from repro.launch.hlo_cost import expanded_cost
-                cost = expanded_cost(compiled.as_text(), num_devices=1)
-                t_gemm = cost.flops / _ROOFLINE_FLOPS_S
-                t_other = cost.bytes / _ROOFLINE_BYTES_S
-                if t_gemm + t_other > 0.0:
-                    gemm_frac = min(0.95, max(0.05,
-                                              t_gemm / (t_gemm + t_other)))
-            except Exception:
-                gemm_frac = None          # attribution is best-effort
             self.bundle = StepBundle(
                 grad_step=compiled,
                 opt_step=jax.jit(opt_fn, donate_argnums=(0, 1, 2)),
-                gemm_frac=gemm_frac)
+                gemm_frac=gemm_fraction(compiled))
         return self.bundle
 
     def train_iteration(self, params, opt_state, tracer=None):
@@ -232,6 +233,7 @@ class Trainer:
         params, opt_state, start = self.init_state()
         n = steps or self.tc.steps
         tracer = self.pt.tracer if self.pt else None
+        t_log, step_log = time.perf_counter(), start
         for step in range(start, start + n):
             batch_np = self._next()
             batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
@@ -246,6 +248,11 @@ class Trainer:
             self._opt_anchor()
             if (step + 1) % self.tc.log_every == 0 or step == start:
                 m = {k: float(v) for k, v in metrics.items()}
+                # host seconds per step since the previous entry (the
+                # float() above waits for this step's result)
+                now = time.perf_counter()
+                m["step_s"] = (now - t_log) / (step + 1 - step_log)
+                t_log, step_log = now, step + 1
                 self.history.append({"step": step + 1, **m})
                 print(f"step {step+1:5d} loss {m['loss']:.4f} "
                       f"nll {m['nll']:.4f} gnorm {m['grad_norm']:.3f} "

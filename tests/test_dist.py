@@ -33,12 +33,13 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp
         from repro.configs.registry import ARCHS, reduced
         from repro.dist.sharding import DistCtx
+        from repro.launch.mesh import make_mesh
         from repro.models.transformer import Transformer
         from repro.models.io import synth_batch
         from repro.optim.adamw import AdamW, OptConfig
         from repro.train.step import make_train_step
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = reduced(ARCHS["granite-34b"], d_model=64).with_overrides(
             num_heads=4, num_kv_heads=4, vocab_size=512)
         batch = synth_batch(cfg, "train", 4, 32)
@@ -82,9 +83,10 @@ def test_moe_expert_parallel_matches_local():
         import jax, jax.numpy as jnp
         from repro.configs.registry import ARCHS, reduced
         from repro.dist.sharding import DistCtx
+        from repro.launch.mesh import make_mesh
         from repro.models import moe as M
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = reduced(ARCHS["deepseek-v2-lite-16b"], d_model=64)
         cfg = cfg.with_overrides(num_experts=8, top_k=2,
                                  capacity_factor=8.0)
@@ -116,17 +118,18 @@ def test_elastic_checkpoint_restore_new_mesh(tmp_path):
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt.checkpoint import Checkpointer
+        from repro.launch.mesh import make_mesh
 
         tree = {{"w": jnp.arange(64.0).reshape(8, 8)}}
-        mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh1 = make_mesh((2, 4), ("data", "model"))
         sh1 = {{"w": NamedSharding(mesh1, P("data", "model"))}}
         t1 = jax.device_put(tree, sh1)
         ck = Checkpointer("{tmp_path}")
         ck.save(1, t1, async_=False)
 
         # 'failure': restore onto a smaller mesh (2 hosts dropped)
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"),
-                              devices=jax.devices()[:4])
+        mesh2 = make_mesh((2, 2), ("data", "model"),
+                          devices=jax.devices()[:4])
         sh2 = {{"w": NamedSharding(mesh2, P("data", "model"))}}
         t2, meta = ck.restore(1, tree, sh2)
         assert t2["w"].sharding == sh2["w"]
@@ -143,10 +146,10 @@ def test_grad_compression_psum():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.dist.compat import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.optim.compress import psum_compressed
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         g = jnp.asarray(np.random.default_rng(0).normal(
             0, 1, (8, 32)), jnp.float32)
 
@@ -156,8 +159,8 @@ def test_grad_compression_psum():
             exact, _ = psum_compressed({"g": gl[0]}, "pod", "none")
             return out_bf16["g"], out_int8["g"], exact["g"]
 
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("pod"),
-                              out_specs=P()))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                                  out_specs=P()))
         b16, i8, exact = f(g)
         e1 = float(jnp.max(jnp.abs(b16 - exact)))
         e2 = float(jnp.max(jnp.abs(i8 - exact)))

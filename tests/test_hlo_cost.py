@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.hlo_cost import expanded_cost, parse_module
+from repro.launch.hlo_cost import expanded_cost, parse_module, peak_rates
 
 
 def _cost_of(fn, *specs):
@@ -77,3 +77,11 @@ ENTRY %main (x: f32[8]) -> f32[] {
     comps, entry = parse_module(txt)
     assert entry == "%main"
     assert "%add" in comps
+
+
+def test_peak_rates_by_device_kind():
+    """v5e carries its published peaks; a kind missing from the table is an
+    error, never a default."""
+    assert peak_rates("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no roofline peaks"):
+        peak_rates("TPU v99")

@@ -8,6 +8,7 @@ import pytest
 from _prop import given, settings, st   # hypothesis or graceful skip
 
 from repro.kernels import ops, ref
+from repro.kernels.pattern_summary import TILE, row_targets
 
 
 # -- flash attention -----------------------------------------------------------
@@ -79,13 +80,21 @@ def test_ssd_matches_model_chunked_path():
 
 # -- pattern summary -------------------------------------------------------------
 
+def _kernel(u):
+    """The kernel on (E, n) rows, with the targets the backend passes."""
+    u = np.asarray(u, np.float32)
+    return np.asarray(ops.pattern_summary(jnp.asarray(u),
+                                          jnp.asarray(row_targets(u))),
+                      np.float64)
+
+
 def test_pattern_summary_basic(rng):
     E, n = 16, 256
     u = np.clip(rng.normal(0.5, 0.3, (E, n)), 0, 1)
     u[:, :40] = 0
     u[3, 100:180] = 0
     u[5] = 0
-    out = np.asarray(ops.pattern_summary(jnp.asarray(u, jnp.float32)))
+    out = _kernel(u)
     exp = ref.pattern_summary_oracle(u)
     np.testing.assert_allclose(out, exp, atol=1e-5)
 
@@ -101,9 +110,55 @@ def test_pattern_summary_property(e_rows, zero_blocks, data):
         a = rng.integers(0, n - 2)
         b = rng.integers(a + 1, n)
         u[i, a:b] = 0
-    out = np.asarray(ops.pattern_summary(jnp.asarray(u, jnp.float32)))
+    out = _kernel(u)
     exp = ref.pattern_summary_oracle(u)
     np.testing.assert_allclose(out, exp, atol=2e-5)
-    # mu/sigma/frac bounded
+    # mu bounded, counts are whole samples inside the row
     assert (out[:, 0] >= -1e-6).all() and (out[:, 0] <= 1 + 1e-6).all()
-    assert (out[:, 2] > 0).all() and (out[:, 2] <= 1 + 1e-6).all()
+    assert (out[:, 2] > 0).all() and (out[:, 2] <= n).all()
+    np.testing.assert_array_equal(out[:, 2], np.rint(out[:, 2]))
+
+
+@pytest.mark.parametrize("n,edge", [(20_000, TILE), (20_000, 2 * TILE),
+                                    (16_500, TILE)])
+def test_pattern_summary_multi_tile_matches_numpy(n, edge):
+    """Rows spanning several sample tiles (prefix state carried between
+    tiles) give the numpy backend's results: moments to 1e-5, counts
+    exactly -- including all-zero, single-sample and full-window rows and
+    zero runs that straddle the tile edge at ``edge``."""
+    from repro.summarize import get_backend
+    rng = np.random.default_rng((n, edge))
+    u = np.clip(rng.normal(0.45, 0.3, (11, n)), 0, 1).astype(np.float32)
+    u[0] = 0.0                                  # all-zero
+    u[1] = 0.0
+    u[1, n // 2] = 0.7                          # single sample
+    u[2] = 0.5                                  # full window
+    u[3, edge - 40:edge + 60] = 0.0             # gap across a tile edge
+    u[4, :edge + 5] = 0.0                       # leading zeros past a tile
+    u[5, edge - 3:] = 0.0                       # trailing zeros from a tile
+    u[6, 50:n - 50] = 0.0                       # two bursts, equal-ish mass
+    out = _kernel(u)
+    exp = get_backend("numpy").batch_stats(u)
+    np.testing.assert_allclose(out[:, :2], exp[:, :2], atol=1e-5)
+    np.testing.assert_array_equal(out[:, 2], exp[:, 2])
+
+
+def test_pattern_summary_exact_mass_fraction_matches_numpy():
+    """A region holding exactly 80% of a long row's mass is feasible, as
+    the numpy backend's f64 target with its 1e-9 slack says.  Samples are
+    multiples of 1/128, so the region's prefix sums (under 2**17) are exact
+    in f32, while the row total passes 2**17 and its f32 sum is not: only
+    the host's f64 total puts the line where numpy puts it."""
+    from repro.summarize import get_backend
+    n, sizes = 200_000, range(32_000, 36_000, 500)
+    rng = np.random.default_rng(80)
+    u = np.zeros((len(sizes), n), np.float32)
+    for i, b in enumerate(sizes):
+        tail = rng.integers(96, 129, b) / 128.0   # region B: 20% of the mass
+        u[i, :4 * b] = rng.permutation(np.tile(tail, 4))  # A: exactly 80%
+        u[i, n - b:] = tail                       # a long zero gap between
+    out = _kernel(u)
+    exp = get_backend("numpy").batch_stats(u)
+    np.testing.assert_array_equal(exp[:, 2], [4 * b for b in sizes])
+    np.testing.assert_allclose(out[:, :2], exp[:, :2], atol=1e-5)
+    np.testing.assert_array_equal(out[:, 2], exp[:, 2])

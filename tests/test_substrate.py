@@ -79,6 +79,21 @@ def test_data_determinism_and_sharding():
     assert (b0["labels"] < cfg.vocab_size).all()
 
 
+def test_synthetic_lm_follows_its_recurrence():
+    """Between restarts every row follows tok[t+1] = (31*tok[t] + 17*m) % V
+    for one m in 1..7, and restarts are about 5% of positions."""
+    cfg = reduced(ARCHS["granite-34b"])
+    V = cfg.vocab_size
+    b = SyntheticLM(cfg, DataConfig(batch=8, seq_len=512)).batch_at(3)
+    x = np.concatenate([b["tokens"], b["labels"][:, -1:]], axis=1)
+    np.testing.assert_array_equal(b["labels"][:, :-1], b["tokens"][:, 1:])
+    for row in x.astype(np.int64):
+        d = (row[1:] - 31 * row[:-1]) % V
+        vals, counts = np.unique(d, return_counts=True)
+        assert vals[counts.argmax()] in {17 * m % V for m in range(1, 8)}
+        assert 0.88 < counts.max() / len(d) <= 1.0
+
+
 def test_dataloader_prefetch_and_anchor():
     cfg = reduced(ARCHS["granite-34b"])
     src = SyntheticLM(cfg, DataConfig(batch=2, seq_len=16))
@@ -158,6 +173,21 @@ def test_moe_gates_and_capacity():
     E = cfg.num_experts
     counts = stats[:E]
     assert float(counts.sum()) == 2 * 16 * cfg.top_k   # no drops at cf=E
+
+
+@pytest.mark.parametrize("which", ["moe", "mlp"])
+def test_gated_weights_init_at_fan_in_d(which):
+    """Gate and up projections are drawn at std 1/sqrt(d_model), like every
+    other d -> ff projection; the (d, 2, ff) layout does not make 2 the
+    fan-in."""
+    from repro.models import layers as L
+    cfg = _moe_cfg()
+    d = cfg.d_model
+    p = (M.init_moe(jax.random.PRNGKey(0), cfg) if which == "moe"
+         else L.init_mlp(jax.random.PRNGKey(0), d, 256, "swiglu", False))
+    std = float(jnp.std(p["wi"]))
+    # truncated at 2 sigma: the sample std is about 0.88 / sqrt(d)
+    assert 0.8 / d ** 0.5 < std < 0.95 / d ** 0.5, std
 
 
 def test_moe_dropping_reduces_tokens():

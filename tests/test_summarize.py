@@ -298,3 +298,33 @@ def test_available_backends_reports_all_three():
     assert "python" in names and "numpy" in names
     # pallas present in this image (jax + interpret mode)
     assert "pallas" in names
+
+
+def test_pallas_that_fails_to_load_raises(monkeypatch):
+    """With jax installed, a kernel that cannot load is an error, never a
+    silent drop to the host backends."""
+    from repro.summarize.backends import PallasBackend
+
+    def broken(self):
+        raise ImportError("kernel module is broken")
+    monkeypatch.setattr(PallasBackend, "_modules", broken)
+    with pytest.raises(ImportError, match="broken"):
+        PallasBackend().available()
+
+
+def test_pallas_buckets_shapes_and_interprets_off_tpu():
+    """Off the TPU the kernel interprets; every call pads (E, n) to
+    power-of-two buckets, so varying windows reuse a few shapes."""
+    import jax
+    from repro.summarize.backends import PallasBackend
+    be = PallasBackend()
+    assert be.interpret() == (jax.default_backend() != "tpu")
+    ref = get_backend("numpy")
+    rng = np.random.default_rng(1)
+    for E, n in [(3, 100), (5, 120), (9, 300)]:
+        u = np.clip(rng.normal(0.4, 0.3, (E, n)), 0, 1).astype(np.float32)
+        u[0] = 0.0
+        out, want = be.batch_stats(u), ref.batch_stats(u)
+        np.testing.assert_allclose(out[:, :2], want[:, :2], atol=1e-5)
+        np.testing.assert_array_equal(out[:, 2], want[:, 2])
+    assert be.shapes == {(8, 128), (16, 512)}

@@ -246,3 +246,97 @@ def test_multiprocess_trainer_scenario(fault, functions, workers, action):
     assert ws["expected"] == 4 * N_WIN
     assert ws["delivered"] == ws["expected"]
     _incident(res, functions, workers, action)
+
+
+# -- one process per chip ------------------------------------------------------
+
+def _fake_host(monkeypatch, root, tpus, nodes=(), accel=0):
+    """A host with ``tpus`` TPU v5e functions on its PCI bus (in IOMMU
+    groups 0..tpus-1) and a gVNIC, of which this process sees the vfio
+    nodes of the groups ``nodes`` and ``accel`` /dev/accel nodes."""
+    import repro.online.scenario as scenario
+    pci, dev = root / "pci", root / "dev"
+    (dev / "vfio").mkdir(parents=True)
+    (dev / "vfio" / "vfio").touch()
+    for i, ident in enumerate(["0x0063"] * tpus + ["0x0042"]):
+        d = pci / f"0000:00:{i:02x}.0"
+        d.mkdir(parents=True)
+        (d / "vendor").write_text("0x1ae0\n")
+        (d / "device").write_text(ident + "\n")
+        (root / "groups" / str(i)).mkdir(parents=True)
+        (d / "iommu_group").symlink_to(root / "groups" / str(i))
+    for g in nodes:
+        (dev / "vfio" / str(g)).touch()
+    for n in range(accel):
+        (dev / f"accel{n}").touch()
+    monkeypatch.setattr(scenario, "_PCI_DEVICES", str(pci))
+    monkeypatch.setattr(scenario, "_DEV", str(dev))
+
+
+def test_trainer_mp_refuses_more_processes_than_chips(monkeypatch,
+                                                      tmp_path):
+    """Every trainer child needs a chip of its own: on a one-chip host a
+    second trainer process is refused before anything is spawned."""
+    _fake_host(monkeypatch, tmp_path, 1, nodes=(0,))
+    r = _scenario(TrainerWorkload(n_workers=4), DataloaderBurn(workers=(1,)))
+    with pytest.raises(RuntimeError, match="need a chip each"):
+        r.run_multiprocess(n_procs=2)
+
+
+@pytest.mark.parametrize("tpus,nodes,accel,chips", [
+    (4, (0, 1, 2, 3), 0, 4),        # a whole four-chip host over vfio
+    (4, (3,), 0, 1),                # one chip of four exposed to a sandbox
+    (4, (), 4, 4),                  # chips on the accel driver
+    (0, (), 0, 0),                  # no TPU: the gVNIC is not a chip
+])
+def test_chip_count_ignores_the_jax_backend(monkeypatch, tmp_path, tpus,
+                                            nodes, accel, chips):
+    """Chips are counted from the PCI bus and the device nodes this
+    process sees, not through a jax backend: a process whose backend is
+    the CPU, as one whose TPU init failed would be, still counts them.
+    (That counting starts no backend is checked by
+    test_multiprocess_parents_stay_off_jax.)"""
+    import jax
+    from repro.online.scenario import accelerator_chips
+    assert jax.default_backend() == "cpu"
+    _fake_host(monkeypatch, tmp_path, tpus, nodes, accel)
+    assert accelerator_chips() == chips
+
+
+@pytest.mark.timeout(600)
+def test_multiprocess_parents_stay_off_jax():
+    """Neither multiprocess parent (simulator or trainer children)
+    initializes a jax backend: on a chip it would hold the chip its
+    children need.  Checked in a fresh interpreter."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+    code = textwrap.dedent("""
+        from jax._src import xla_bridge
+        from repro.core.simulation import SimConfig
+        from repro.online import ScenarioRunner, ScheduledFault
+        from repro.train.workload import (DataloaderBurn, TrainerWorkload,
+                                          default_trainer_detector_cfg,
+                                          tiny_train_setup)
+        sim = ScenarioRunner(SimConfig(n_workers=4, window_s=0.5), [],
+                             n_windows=2).run_multiprocess(n_procs=2)
+        assert sim.wire_summary()["delivered"] == 8
+        assert not xla_bridge.backends_are_initialized()
+        wl = TrainerWorkload(n_workers=2, setup=tiny_train_setup(),
+                             warmup_iters=2)
+        res = ScenarioRunner(
+            None, [ScheduledFault(DataloaderBurn(workers=(1,)), 1, 2)],
+            n_windows=2, iters_per_window=3,
+            detector_cfg=default_trainer_detector_cfg(3),
+            workload=wl).run_multiprocess(n_procs=1, window_timeout=240.0)
+        assert res.wire_summary()["delivered"] == 4
+        assert not xla_bridge.backends_are_initialized()
+        print("OK")
+    """)
+    src = str(Path(__file__).resolve().parent.parent / "src")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=540,
+                       env={**os.environ, "PYTHONPATH": src})
+    assert r.returncode == 0 and "OK" in r.stdout, r.stdout + r.stderr

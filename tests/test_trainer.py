@@ -58,3 +58,28 @@ def test_perftracker_triggers_on_injected_fault(tmp_path):
     from repro.core.mitigation import Action
     assert any(p.action == Action.MIGRATE_DATALOADER
                for _, p in tr.mitigations)
+
+
+def test_profiling_window_holds_min_iterations(monkeypatch):
+    """A profiling window closes at its deadline only once it holds
+    ``MIN_WINDOW_ITERS`` whole iterations: an iteration slower than the
+    window still gives the diagnosis a pattern."""
+    from repro.core.detector import Trigger
+    from repro.instrument import hooks
+    pt = hooks.PerfTracker(hooks.PerfTrackerConfig(window_s=0.0))
+    closed = []
+
+    def finish():
+        closed.append(pt._window_iters)
+        pt._window_deadline = None
+    monkeypatch.setattr(pt, "_finish_window", finish)
+    triggers = iter([Trigger("slowdown", 0.0, 1.0, 0.5)])
+    monkeypatch.setattr(pt.service.detector, "feed",
+                        lambda name, t: next(triggers, None))
+    loader_next, opt_step = pt.wrap(lambda: None, lambda: None)
+    opt_step()                       # the trigger opens a window
+    for _ in range(hooks.MIN_WINDOW_ITERS):
+        assert not closed            # past its deadline, too few iterations
+        loader_next()
+        opt_step()
+    assert closed == [hooks.MIN_WINDOW_ITERS]

@@ -125,9 +125,10 @@ def test_client_backpressure_drops_oldest_counts_on_wire():
     """A stalled wire (blocking frame filter) fills the bounded queue; the
     oldest unsent windows drop, and the window_end frame — snapshotted at
     SEND time — carries the final counters to the collector."""
-    gate = threading.Event()
+    gate, stalled = threading.Event(), threading.Event()
 
     def stall(msg, frame):
+        stalled.set()
         gate.wait(timeout=30.0)
         return None
 
@@ -136,7 +137,10 @@ def test_client_backpressure_drops_oldest_counts_on_wire():
         client = WireClient(server.address, worker=0, max_queue=2,
                             frame_filter=stall)
         try:
-            for w in range(6):
+            client.send_upload(0, _upload(0))
+            # queue the rest only once the sender holds window 0
+            assert stalled.wait(timeout=10.0)
+            for w in range(1, 6):
                 client.send_upload(w, _upload(0))
             # sender thread is stalled inside window 0's filter; of the 5
             # queued behind it, only the newest 2 survive
