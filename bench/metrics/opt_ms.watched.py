@@ -1,0 +1,9 @@
+"""opt_ms.watched: device milliseconds per training step of the watched
+job's optimizer: operations in the step's ``opt`` scope (the AdamW
+update).  Read from each device operation's scope path in the trace, per
+``repro.train.step`` span of the window (``bench.program_spans``)."""
+from bench import program_spans
+
+
+def read(ctx):
+    return program_spans.phase_ms(ctx, "opt")

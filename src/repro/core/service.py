@@ -24,7 +24,6 @@ invariant).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -38,6 +37,7 @@ from repro.core.mitigation import (MitigationPlan, format_plans,
                                    plan_mitigations)
 from repro.core.report import (Diagnosis, build_report, format_report,
                                format_transport)
+from repro.instrument.spans import span
 from repro.summarize.aggregate import PatternAggregator
 from repro.summarize.fleet import summarize_fleet
 
@@ -47,6 +47,15 @@ class DiagnosisResult:
     trigger: Optional[Trigger]
     diagnoses: List[Diagnosis]
     fleet_size: int
+    #: host seconds by stage, each the elapsed time of the program's host
+    #: span of that stage: ``summarize_s`` (``repro.service.summarize``:
+    #: the fleet batch, or every worker's summarize and upload framing on
+    #: the wire), ``transport_s`` (wire only, ``repro.service.transport``:
+    #: the loopback wire's connections, send round and teardown),
+    #: ``localize_s`` (``repro.service.localize``: aggregating the
+    #: uploads or finalizing the fleet block, and localizing; not the
+    #: report), and on the wire ``upload_summarize_s``, the daemons' own
+    #: summed summarize clocks
     timing: Dict[str, float]
     pattern_bytes: int
     raw_bytes: int
@@ -146,7 +155,7 @@ class PerfTrackerService:
         instead of crashing or polluting the fleet median."""
         if fleet_size is None:
             fleet_size = len(batch.expected)
-        t1 = time.perf_counter()
+        loc = span("repro.service.localize")
         if hasattr(batch, "aggregate"):
             # collector-tree window (transport.TreeWindowBatch): shard
             # blocks scatter straight into the aggregator — the per-worker
@@ -164,7 +173,7 @@ class PerfTrackerService:
         pats, kinds = agg.finalize()
         abn = self.localizer.localize(pats, kinds, present=present)
         timing = dict(timing or {})
-        timing["localize_s"] = time.perf_counter() - t1
+        timing["localize_s"] = loc.end()
         timing["upload_summarize_s"] = summarize_s
         return DiagnosisResult(
             trigger=trigger,
@@ -189,25 +198,26 @@ class PerfTrackerService:
         byte-identical between the two.
         """
         timing = {}
-        t0 = time.perf_counter()
         if mode == "fleet":
-            fs = summarize_fleet(profiles, kind_of,
-                                 backend=self.summarize_backend)
-            timing["summarize_s"] = time.perf_counter() - t0
-            t1 = time.perf_counter()
+            with span("repro.service.summarize") as summ:
+                fs = summarize_fleet(profiles, kind_of,
+                                     backend=self.summarize_backend)
+            timing["summarize_s"] = summ.seconds
+            loc = span("repro.service.localize")
             agg, kinds = fs.agg.finalize()
             pattern_bytes = fs.pattern_bytes
         elif mode == "wire":
             from repro.transport import LoopbackWire
-            uploads = [summarize_and_upload(p, kind_of,
-                                            backend=self.summarize_backend)
-                       for p in profiles]
-            timing["summarize_s"] = time.perf_counter() - t0
-            t2 = time.perf_counter()
-            with LoopbackWire([p.worker for p in profiles],
-                              frame_filter=self.wire_frame_filter) as wire:
+            with span("repro.service.summarize") as summ:
+                uploads = [summarize_and_upload(
+                    p, kind_of, backend=self.summarize_backend)
+                    for p in profiles]
+            timing["summarize_s"] = summ.seconds
+            with span("repro.service.transport") as sent, LoopbackWire(
+                    [p.worker for p in profiles],
+                    frame_filter=self.wire_frame_filter) as wire:
                 batch = wire.send_round(uploads, window=0)
-            timing["transport_s"] = time.perf_counter() - t2
+            timing["transport_s"] = sent.seconds
             row_of = {p.worker: i for i, p in enumerate(profiles)}
             res = self.diagnose_batch(batch, fleet_size=len(profiles),
                                       row_of=row_of, trigger=trigger,
@@ -220,7 +230,7 @@ class PerfTrackerService:
             raise ValueError(f"unknown diagnosis mode {mode!r}; "
                              "expected 'fleet' or 'wire'")
         abn = self.localizer.localize(agg, kinds)
-        timing["localize_s"] = time.perf_counter() - t1
+        timing["localize_s"] = loc.end()
         return DiagnosisResult(
             trigger=trigger,
             diagnoses=build_report(abn, len(profiles)),
@@ -233,9 +243,9 @@ class PerfTrackerService:
                           kinds: Dict[str, Kind]) -> DiagnosisResult:
         """Pattern-mode entry (scaling benchmarks / pre-aggregated fleets)."""
         W = next(iter(patterns.values())).shape[0] if patterns else 0
-        t0 = time.perf_counter()
-        abn = self.localizer.localize(patterns, kinds)
-        dt = time.perf_counter() - t0
+        with span("repro.service.localize") as loc:
+            abn = self.localizer.localize(patterns, kinds)
         return DiagnosisResult(
             trigger=None, diagnoses=build_report(abn, W), fleet_size=W,
-            timing={"localize_s": dt}, pattern_bytes=0, raw_bytes=0)
+            timing={"localize_s": loc.seconds}, pattern_bytes=0,
+            raw_bytes=0)

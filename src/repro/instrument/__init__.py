@@ -1,2 +1,3 @@
-from repro.instrument.hooks import PerfTracker, PerfTrackerConfig  # noqa: F401
-from repro.instrument.tracer import HostSampler, Tracer  # noqa: F401
+"""The attachment to a training job: anchors and profiling windows
+(``hooks``), the phase tracer and samplers (``tracer``), and the host spans
+on the profiler's clock (``spans``)."""

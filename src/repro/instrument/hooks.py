@@ -15,6 +15,7 @@ from typing import Callable, List, Optional
 from repro.core.detector import DetectorConfig, Trigger
 from repro.core.events import Kind
 from repro.core.service import DiagnosisResult, PerfTrackerService
+from repro.instrument.spans import span
 from repro.instrument.tracer import Tracer
 
 #: a window closes at its deadline only once it holds this many whole
@@ -34,7 +35,10 @@ class PerfTrackerConfig:
 
 class PerfTracker:
     """Single-worker online attachment. In a fleet, one instance runs per
-    worker and uploads patterns to the global service (see core.service)."""
+    worker and uploads patterns to the global service (see core.service).
+
+    A profiling window is the host span ``repro.tracker.window``, from its
+    trigger to the end of its diagnosis (``repro.tracker.diagnose``)."""
 
     def __init__(self, cfg: PerfTrackerConfig = PerfTrackerConfig(),
                  worker: int = 0):
@@ -47,6 +51,7 @@ class PerfTracker:
         self._window_iters = 0
         self.last_trigger: Optional[Trigger] = None
         self.results: List[DiagnosisResult] = []
+        self._window_span: Optional[span] = None
 
     # -- anchors -----------------------------------------------------------
     def _on_anchor(self, name: str):
@@ -55,6 +60,7 @@ class PerfTracker:
         if trig is not None and self.cfg.auto_profile \
                 and self._window_deadline is None:
             self.last_trigger = trig
+            self._window_span = span("repro.tracker.window")
             self.tracer.start_window()
             self._window_deadline = now + self.cfg.window_s
             self._window_iters = 0
@@ -70,10 +76,14 @@ class PerfTracker:
         # wire mode: the true single-worker daemon shape — and it reuses
         # the (E, n) batch the tracer pre-packed onto profile.packed, which
         # the fleet-wide gather path would rebuild from raw streams
-        res = self.service.diagnose_profiles([profile],
-                                             trigger=self.last_trigger,
-                                             mode="wire")
+        with span("repro.tracker.diagnose"):
+            res = self.service.diagnose_profiles([profile],
+                                                 trigger=self.last_trigger,
+                                                 mode="wire")
         self.results.append(res)
+        if self._window_span is not None:
+            self._window_span.end()
+            self._window_span = None
 
     def flush(self) -> Optional[DiagnosisResult]:
         if self._window_deadline is not None:

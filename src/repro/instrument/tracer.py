@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.events import FunctionEvent, Kind, SampleStream, WorkerProfile
+from repro.instrument.spans import span
 
 
 def _read_proc_stat() -> Tuple[float, float]:
@@ -156,6 +157,13 @@ class Tracer:
             s.start()
 
     def stop_window(self) -> WorkerProfile:
+        """Ends the window: stops the samplers (host span
+        ``repro.tracer.stop``) and packs the profile (``repro.tracer.pack``
+        inside it)."""
+        with span("repro.tracer.stop", worker=self.worker):
+            return self._stop_window()
+
+    def _stop_window(self) -> WorkerProfile:
         self.active = False
         t0 = self._window_start
         streams: Dict[str, SampleStream] = {}
@@ -172,7 +180,8 @@ class Tracer:
             streams=streams)
         if self.pack:
             from repro.summarize.packing import pack_profile
-            profile.packed = pack_profile(profile)
+            with span("repro.tracer.pack"):
+                profile.packed = pack_profile(profile)
         return profile
 
     @contextmanager

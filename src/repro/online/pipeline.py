@@ -14,7 +14,6 @@ components into the continuous loop the paper ran for 1.5 years.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -29,6 +28,7 @@ from repro.core.localizer import Abnormality
 from repro.core.report import (Diagnosis, build_report, format_report,
                                format_transport)
 from repro.core.service import PerfTrackerService
+from repro.instrument.spans import span
 from repro.online.ema import EmaPatternAggregator
 from repro.online.escalation import EscalationPolicy
 from repro.online.incident import Incident, IncidentManager
@@ -46,7 +46,17 @@ class WindowReport:
     rates: Optional[np.ndarray]    # per-worker rates USED for this window
     raw_bytes: int
     pattern_bytes: int
+    #: host seconds of the tick's summarize stage (span
+    #: ``repro.pipeline.summarize``): packing the profiles, the copy to
+    #: the device, the kernel call and the scatter into the window's
+    #: pattern block; or assembling a wire batch's block.  Not the EMA
+    #: fold (``repro.pipeline.ema``).
     summarize_s: float
+    #: host seconds of the localize stage (``repro.pipeline.localize``):
+    #: mesh membership, localization on the smoothed patterns, numerics
+    #: abnormalities and ``build_report``.  It ends before
+    #: ``IncidentManager.on_window`` (``repro.pipeline.incidents``) and
+    #: the mitigation step (``repro.pipeline.mitigate``).
     localize_s: float
     #: workers whose evidence arrived this window (None = full fleet)
     present: Optional[np.ndarray] = None
@@ -250,8 +260,13 @@ class OnlinePipeline:
         ``present_workers`` maps a PARTIAL profile list to global fleet
         rows (``present_workers[i]`` is ``profiles[i]``'s worker id):
         absent workers' EMA rows freeze instead of decaying on a window
-        they never reported (DESIGN.md §8)."""
-        t0 = time.perf_counter()
+        they never reported (DESIGN.md §8).
+
+        The tick is the host span ``repro.pipeline.tick``, holding one
+        span per stage: ``repro.pipeline.summarize``, ``.ema``,
+        ``.localize``, ``.incidents``, ``.mitigate`` and ``.escalate``."""
+        tick = span("repro.pipeline.tick")
+        summ = span("repro.pipeline.summarize")
         present = None
         if present_workers is not None:
             ids = np.asarray(list(present_workers), np.int64)
@@ -263,12 +278,13 @@ class OnlinePipeline:
         else:
             fs = summarize_fleet(profiles,
                                  backend=self.service.summarize_backend)
-        self.ema.fold(fs.agg, present=present)
-        summarize_s = time.perf_counter() - t0
-        return self._finish_tick(
-            t=t, rates=rates, present=present,
+        summarize_s = summ.end()
+        report = self._finish_tick(
+            fs.agg, t=t, rates=rates, present=present,
             raw_bytes=sum(p.raw_size_bytes() for p in profiles),
             pattern_bytes=fs.pattern_bytes, summarize_s=summarize_s)
+        tick.end()
+        return report
 
     def window_tick_batch(self, batch, t: Optional[float] = None,
                           rates: Optional[np.ndarray] = None
@@ -278,8 +294,10 @@ class OnlinePipeline:
 
         Uploads address EMA rows by worker id; workers whose upload was
         dropped (backpressure, loss) keep their previous smoothed pattern,
-        and the batch's transport counters surface in the report."""
-        t0 = time.perf_counter()
+        and the batch's transport counters surface in the report.  Host
+        spans as ``window_tick``'s."""
+        tick = span("repro.pipeline.tick")
+        summ = span("repro.pipeline.summarize")
         if hasattr(batch, "aggregate"):
             # collector-tree window (transport.TreeWindowBatch): shard
             # blocks were compacted at the leaves; scatter them straight
@@ -293,23 +311,27 @@ class OnlinePipeline:
                                                         self.n_workers)
             raw_bytes = sum(u.raw_bytes for u in uploads)
             pattern_bytes = sum(len(u.payload) for u in uploads)
-        self.ema.fold(agg, present=present)
-        summarize_s = time.perf_counter() - t0
-        return self._finish_tick(
-            t=t, rates=rates, present=present,
+        summarize_s = summ.end()
+        report = self._finish_tick(
+            agg, t=t, rates=rates, present=present,
             raw_bytes=raw_bytes, pattern_bytes=pattern_bytes,
             summarize_s=summarize_s, transport=batch.stats())
+        tick.end()
+        return report
 
-    def _finish_tick(self, t: Optional[float], rates, present,
+    def _finish_tick(self, agg, t: Optional[float], rates, present,
                      raw_bytes: int, pattern_bytes: int, summarize_s: float,
                      transport: Optional[Dict[str, object]] = None
                      ) -> WindowReport:
-        """Shared tail of every tick flavor: localize on the smoothed
-        patterns, advance incidents, retune escalation."""
+        """Shared tail of every tick flavor: fold the window's block into
+        the EMA, localize on the smoothed patterns, advance incidents,
+        retune escalation."""
         if t is None:
             t = float(len(self.windows))
-        pats, kinds = self.ema.finalize()
-        t1 = time.perf_counter()
+        with span("repro.pipeline.ema"):
+            self.ema.fold(agg, present=present)
+            pats, kinds = self.ema.finalize()
+        loc = span("repro.pipeline.localize")
         # mesh membership vs transient presence: a worker whose UPLOAD was
         # lost keeps implicating via its frozen EMA row (DESIGN.md §8), but
         # a worker REPLACED out of the mesh (and a standby not yet in it)
@@ -338,18 +360,22 @@ class OnlinePipeline:
         # hint fractions size over the ACTIVE mesh, like plan sizing —
         # standbys/replaced rows must not dilute them
         diagnoses = build_report(abn, self.incidents.fleet_size)
-        localize_s = time.perf_counter() - t1
-        changed = self.incidents.on_window(
-            t, diagnoses,
-            detector_healthy=(self.detector.healthy
-                              and self.numerics.healthy
-                              and self.slo.healthy))
+        localize_s = loc.end()
+        with span("repro.pipeline.incidents"):
+            changed = self.incidents.on_window(
+                t, diagnoses,
+                detector_healthy=(self.detector.healthy
+                                  and self.numerics.healthy
+                                  and self.slo.healthy))
         mitigations = []
         if self.mitigator is not None:
-            mitigations = self.mitigator.step(self.incidents, t=t,
-                                              window=len(self.windows))
-        escalated = (self.escalation.observe(abn)
-                     if self.escalation else [])
+            with span("repro.pipeline.mitigate"):
+                mitigations = self.mitigator.step(self.incidents, t=t,
+                                                  window=len(self.windows))
+        escalated = []
+        if self.escalation:
+            with span("repro.pipeline.escalate"):
+                escalated = self.escalation.observe(abn)
         report = WindowReport(
             index=len(self.windows), t=t, diagnoses=diagnoses,
             changed=changed, escalated=escalated, rates=rates,

@@ -54,6 +54,7 @@ from repro.core import faults as F
 from repro.core.detector import DetectorConfig
 from repro.core.mitigation import Action
 from repro.core.simulation import FleetSimulator, SimConfig
+from repro.instrument.spans import span
 from repro.online.escalation import EscalationPolicy
 from repro.online.mitigation import MitigationEngine, plan_to_wire
 from repro.online.pipeline import OnlinePipeline, WindowReport
@@ -243,6 +244,9 @@ class ScenarioRunner:
         reports: List[WindowReport] = []
         spans: List[Tuple[float, float]] = []
         for i in range(self.n_windows):
+            # host spans: the window, and in it the workload's window
+            # and the detector's intake
+            window = span("repro.scenario.window", window=i)
             if self.engine is not None:
                 self.engine.begin_window(i)
             faults = self.faults_at(i)
@@ -250,11 +254,13 @@ class ScenarioRunner:
             # at the previous window's tick), so sampling them before the
             # workload runs is byte-identical to the historical loop order
             rates = self.pipeline.rates()
-            wd = self.workload.run_window(i, faults,
-                                          self.iters_per_window, rates)
-            self.pipeline.feed_anchors(wd.anchors)
-            self.pipeline.feed_metrics(wd.metrics)
-            self.pipeline.poll_blockage(wd.clock)
+            with span("repro.workload.window", window=i):
+                wd = self.workload.run_window(i, faults,
+                                              self.iters_per_window, rates)
+            with span("repro.pipeline.anchors"):
+                self.pipeline.feed_anchors(wd.anchors)
+                self.pipeline.feed_metrics(wd.metrics)
+                self.pipeline.poll_blockage(wd.clock)
             # profiles come from the ACTIVE fleet only; with standbys
             # and/or after a re-mesh the absent rows are present-masked
             # and kept out of the mesh membership (the full-fleet path
@@ -275,6 +281,7 @@ class ScenarioRunner:
                 for m in report.mitigations:
                     print(f"   mitigation: {m}")
                 print(report.report(len(active)))
+            window.end()
         return ScenarioResult(pipeline=self.pipeline, reports=reports,
                               spans=spans)
 

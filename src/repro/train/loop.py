@@ -31,6 +31,7 @@ from repro.core.mitigation import Action, plan_mitigations
 from repro.data.pipeline import DataConfig, DataLoader, SyntheticLM
 from repro.dist.sharding import DistCtx
 from repro.instrument.hooks import PerfTracker, PerfTrackerConfig
+from repro.instrument.spans import span, step_span
 from repro.models.transformer import Transformer
 from repro.optim.adamw import AdamW, OptConfig
 from repro.train.step import make_split_train_step, make_train_step
@@ -181,10 +182,18 @@ class Trainer:
         model), ``optimizer.step`` (fenced on the new params), and
         ``ckpt.save`` when a checkpoint interval hits.  ``tracer`` may be
         None or inactive — the loop then runs unobserved (the overhead
-        benchmark's baseline).  Returns ``(params, opt_state, metrics)``.
+        benchmark's baseline).  Every phase is also a host span on the
+        profiler's clock: ``repro.train.iteration`` (worker, iteration)
+        holding ``repro.train.loader`` (batch, host-to-device copy and any
+        injected burn), ``.grad`` (dispatch), ``.fence``, ``.opt``, and
+        ``.ckpt`` / ``.gc`` when they run.
+        Returns ``(params, opt_state, metrics)``.
         """
         ph = tracer.phase if tracer is not None else _noop_phase
-        with ph("dataloader.next", Kind.PYTHON):
+        it = span("repro.train.iteration",
+                  worker=tracer.worker if tracer is not None else 0,
+                  iteration=self._iter)
+        with span("repro.train.loader"), ph("dataloader.next", Kind.PYTHON):
             batch_np = self.loader.next()
             batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
             if self.data_burn_s > 0.0:    # injected fault: CPU-burning loader
@@ -192,13 +201,15 @@ class Trainer:
                 x = 1.0
                 while time.perf_counter() < deadline:
                     x = x * 1.0000001 + 1.0
-        bundle = self.ensure_bundle(params, batch)
         res = self._step_resource
-        t0 = time.perf_counter()
-        grads, metrics = bundle.grad_step(params, batch)
-        if self.step_pad_s > 0.0:         # injected fault: slow device step
-            time.sleep(self.step_pad_s)
-        jax.block_until_ready(grads)
+        with span("repro.train.grad"):
+            bundle = self.ensure_bundle(params, batch)
+            t0 = time.perf_counter()
+            grads, metrics = bundle.grad_step(params, batch)
+            if self.step_pad_s > 0.0:     # injected fault: slow device step
+                time.sleep(self.step_pad_s)
+        with span("repro.train.fence"):
+            jax.block_until_ready(grads)
         t1 = time.perf_counter()
         if tracer is not None and tracer.active:
             tracer.add_event("train.step", Kind.GPU, t0, t1, depth=1,
@@ -209,21 +220,23 @@ class Trainer:
                                  resource=res)
                 tracer.add_event("xla.other", Kind.GPU, cut, t1, depth=2,
                                  resource=res)
-        with ph("optimizer.step", Kind.GPU, resource=res,
-                fence=lambda: new_params):
+        with span("repro.train.opt"), ph("optimizer.step", Kind.GPU,
+                                         resource=res,
+                                         fence=lambda: new_params):
             new_params, new_opt, opt_metrics = bundle.opt_step(
                 grads, opt_state, params)
         self._iter += 1
         if self.ckpt and self.tc.ckpt_every \
                 and self._iter % self.tc.ckpt_every == 0:
-            with ph("ckpt.save", Kind.PYTHON):
+            with span("repro.train.ckpt"), ph("ckpt.save", Kind.PYTHON):
                 self.ckpt.save(self._iter, {"params": new_params,
                                             "opt": new_opt})
         if self.gc_pause_s > 0.0 and self._iter % max(1, self.gc_every) == 0:
             # injected fault: unsynchronized gc stall (C2P3 stand-in)
-            with ph("runtime.gc", Kind.PYTHON):
+            with span("repro.train.gc"), ph("runtime.gc", Kind.PYTHON):
                 gc.collect()
                 time.sleep(self.gc_pause_s)
+        it.end()
         m = dict(metrics)
         m.update(opt_metrics)
         return new_params, new_opt, m
@@ -232,17 +245,17 @@ class Trainer:
     def run(self, steps: Optional[int] = None):
         params, opt_state, start = self.init_state()
         n = steps or self.tc.steps
-        tracer = self.pt.tracer if self.pt else None
+        ph = self.pt.tracer.phase if self.pt else _noop_phase
         t_log, step_log = time.perf_counter(), start
         for step in range(start, start + n):
-            batch_np = self._next()
-            batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-            if tracer:
-                with tracer.phase("train.step", Kind.GPU, depth=1,
-                                  fence=lambda: metrics["loss"]):
-                    params, opt_state, metrics = self._jit_step(
-                        params, opt_state, batch)
-            else:
+            one = step_span("repro.train.step", step)
+            with span("repro.train.loader"):
+                batch_np = self._next()
+                batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+            # the dispatch; inside a tracker window also the fence
+            with span("repro.train.fused"), ph(
+                    "train.step", Kind.GPU, depth=1,
+                    fence=lambda: metrics["loss"]):
                 params, opt_state, metrics = self._jit_step(
                     params, opt_state, batch)
             self._opt_anchor()
@@ -263,6 +276,7 @@ class Trainer:
                                           "opt": opt_state})
             params, opt_state = self._maybe_mitigate(params, opt_state,
                                                      step + 1)
+            one.end()
         if self.ckpt:
             self.ckpt.save(start + n, {"params": params, "opt": opt_state},
                            async_=False)

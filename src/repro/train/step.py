@@ -9,13 +9,31 @@ import jax.numpy as jnp
 from repro.models.transformer import Transformer
 from repro.optim.adamw import AdamW
 
+#: named scopes of the step programs: every operation's ``op_name`` is
+#: ``.../jvp(fwd)/...`` (forward), ``.../transpose(jvp(fwd))/...`` (backward,
+#: with rematerialized forward under ``rematted_computation``) or
+#: ``.../opt/...`` (optimizer update), so a device trace can be split by phase
+FWD_SCOPE, OPT_SCOPE = "fwd", "opt"
+
+
+def _scoped_loss(model: Transformer):
+    def loss(params, batch):
+        with jax.named_scope(FWD_SCOPE):
+            return model.loss(params, batch)
+    return loss
+
+
+def _scoped_update(opt: AdamW, grads, opt_state, params):
+    with jax.named_scope(OPT_SCOPE):
+        return opt.update(grads, opt_state, params)
+
 
 def make_train_step(model: Transformer, opt: AdamW, accum_steps: int = 1):
     """accum_steps > 1: gradient accumulation over micro-batches via
     lax.scan — per-device activation memory scales with the micro-batch
     (HBM-fit lever for the big archs; EXPERIMENTS §Perf H5). The global
     batch is split on the leading axis; grads are averaged."""
-    grad_fn = jax.value_and_grad(model.loss, has_aux=True)
+    grad_fn = jax.value_and_grad(_scoped_loss(model), has_aux=True)
 
     def train_step(params, opt_state, batch):
         if accum_steps == 1:
@@ -39,8 +57,8 @@ def make_train_step(model: Transformer, opt: AdamW, accum_steps: int = 1):
                 lambda g: g / accum_steps, gsum)
             loss = losses.mean()
             metrics = jax.tree_util.tree_map(lambda x: x.mean(), ms)
-        new_params, new_state, opt_metrics = opt.update(
-            grads, opt_state, params)
+        new_params, new_state, opt_metrics = _scoped_update(
+            opt, grads, opt_state, params)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss
@@ -55,7 +73,7 @@ def make_split_train_step(model: Transformer, opt: AdamW):
     ``optimizer.step`` phases).  Numerically identical to
     ``make_train_step(accum_steps=1)``; slightly slower (two dispatches,
     grads round-trip through HBM)."""
-    grad_fn = jax.value_and_grad(model.loss, has_aux=True)
+    grad_fn = jax.value_and_grad(_scoped_loss(model), has_aux=True)
 
     def grad_step(params, batch):
         (loss, metrics), grads = grad_fn(params, batch)
@@ -64,8 +82,8 @@ def make_split_train_step(model: Transformer, opt: AdamW):
         return grads, metrics
 
     def opt_step(grads, opt_state, params):
-        new_params, new_state, opt_metrics = opt.update(
-            grads, opt_state, params)
+        new_params, new_state, opt_metrics = _scoped_update(
+            opt, grads, opt_state, params)
         return new_params, new_state, opt_metrics
 
     return grad_step, opt_step

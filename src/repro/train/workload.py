@@ -40,6 +40,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.detector import DetectorConfig
+from repro.instrument.spans import span
 from repro.online.workload import (WindowData, WorkloadSource,
                                    merge_anchor_durations, merge_numerics,
                                    synth_anchor_events)
@@ -238,16 +239,17 @@ class _TrainWorker:
         metrics — the numerics channel's raw material (DESIGN.md §12a)."""
         if rate is not None:
             self.tracer.set_rate(float(rate))
-        self.tracer.start_window()
-        durs: List[float] = []
-        self.window_numerics: List[Tuple[float, float]] = []
-        for _ in range(iters):
-            durs.append(self.step())
-            m = self.last_metrics or {}
-            self.window_numerics.append(
-                (float(m.get("loss", 0.0)),
-                 float(m.get("grad_norm", 0.0))))
-        return durs, self.tracer.stop_window()
+        with span("repro.worker.window", worker=self.worker):
+            self.tracer.start_window()
+            durs: List[float] = []
+            self.window_numerics: List[Tuple[float, float]] = []
+            for _ in range(iters):
+                durs.append(self.step())
+                m = self.last_metrics or {}
+                self.window_numerics.append(
+                    (float(m.get("loss", 0.0)),
+                     float(m.get("grad_norm", 0.0))))
+            return durs, self.tracer.stop_window()
 
     def close(self) -> None:
         self.trainer.loader.close()

@@ -19,7 +19,8 @@ def test_compile_cache_follows_env(monkeypatch, tmp_path):
     seen = _cache_dir_set_by(monkeypatch)
     monkeypatch.setenv(cache.ENV_CACHE_DIR, str(tmp_path))
     assert cache.enable_compile_cache() == str(tmp_path)
-    assert seen == {}                  # jax reads the variable itself
+    # jax reads the variable itself; the key holds the programs' metadata
+    assert seen == {cache.METADATA_IN_KEY: True}
 
 
 def test_compile_cache_defaults_to_checkout_root(monkeypatch):
@@ -29,7 +30,8 @@ def test_compile_cache_defaults_to_checkout_root(monkeypatch):
     root = cache.CHECKOUT_CACHE.parent
     assert where == str(root / ".jax_cache")
     assert (root / "src" / "repro").is_dir()
-    assert seen == {"jax_compilation_cache_dir": where}
+    assert seen == {"jax_compilation_cache_dir": where,
+                    cache.METADATA_IN_KEY: True}
 
 
 def test_parse_mesh_uses_the_devices_as_they_are():
