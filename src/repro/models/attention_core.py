@@ -4,6 +4,17 @@ Forward saves only (q, k, v, out, lse); the backward recomputes block
 probabilities — O(B*S*d) residual memory instead of O(S^2) scan residuals
 (verified against naive autodiff in tests/test_attention.py).
 
+Visible blocks: the unfolded path computes only the (q block, kv block)
+pairs holding at least one unmasked (query, key) pair.  For q block i the
+kv blocks it sees are one contiguous range ``[j_lo(i), j_hi(i)]``
+(``_kv_range``): causal attention ends it at the block holding the block's
+last query, a window starts it at the block holding its first query's
+oldest visible key, and a ``kv_len`` ends it at the last live key.  The
+backward walks the transposed range of q blocks for each kv block
+(``_q_range``) and accumulates dq in place.  A skipped block held only
+masked scores, which contributed exact zeros; blocks on a mask's edge keep
+their element mask.  ``visible_blocks`` lists the pairs visited.
+
 Causal folding (``spec.folded``): q blocks are paired (i, NQ-1-i); each pair
 runs an inner scan of exactly NQ+1 block-updates where iteration t updates
   - pair-low  with kv block t          while t <= i_lo,
@@ -23,6 +34,10 @@ import jax.numpy as jnp
 
 Array = jax.Array
 NEG_INF = -1.0e30
+#: named scope of the core's forward and backward: every operation of the
+#: core carries an ``attn`` component in its ``op_name``, so a device trace
+#: can tell attention's time in each phase
+ATTN_SCOPE = "attn"
 
 
 class AttnSpec(NamedTuple):
@@ -77,6 +92,50 @@ def _split_blocks(x, n, bs):
     return x.reshape((B, n, bs) + x.shape[2:]).swapaxes(0, 1)
 
 
+def _kv_range(i, spec: AttnSpec, BQ, BK, NK, q_offset, kv_len):
+    """Inclusive range [lo, hi] of the kv blocks q block ``i`` sees (empty
+    when lo > hi); ``i``, ``q_offset`` and ``kv_len`` may be traced."""
+    lo, hi = 0, NK - 1
+    first = q_offset + i * BQ                   # the block's first query
+    if spec.causal:
+        hi = jnp.minimum(hi, (first + BQ - 1) // BK)
+    if kv_len is not None:
+        hi = jnp.minimum(hi, (kv_len - 1) // BK)
+    if spec.window:
+        oldest = first - spec.window + 1        # the oldest key it sees
+        lo = jnp.maximum(lo, oldest // BK)
+        if kv_len is not None:
+            hi = jnp.where(kv_len - 1 >= oldest, hi, -1)
+    return lo, hi
+
+
+def _q_range(j, spec: AttnSpec, BQ, BK, NQ, q_offset, kv_len):
+    """Inclusive range [lo, hi] of the q blocks that see kv block ``j``:
+    the transpose of ``_kv_range``."""
+    lo, hi = 0, NQ - 1
+    last = (j + 1) * BK - 1                     # the block's last live key
+    if kv_len is not None:
+        last = jnp.minimum(last, kv_len - 1)
+        hi = jnp.where(j * BK < kv_len, hi, -1)
+    if spec.causal:
+        lo = jnp.maximum(lo, (j * BK - q_offset) // BQ)
+    if spec.window:
+        hi = jnp.minimum(hi, (last + spec.window - 1 - q_offset) // BQ)
+    return lo, hi
+
+
+def visible_blocks(spec: AttnSpec, Sq: int, Skv: int, BQ: int, BK: int,
+                   q_offset: int = 0, kv_len=None):
+    """The (q block, kv block) pairs the unfolded path computes, in order."""
+    NQ, NK = Sq // BQ, Skv // BK
+    out = []
+    for i in range(NQ):
+        lo, hi = _kv_range(i, spec, BQ, BK, NK, q_offset, kv_len)
+        out += [(i, j) for j in range(int(lo), int(hi) + 1)]
+    return out
+
+
+@jax.named_scope(ATTN_SCOPE)
 def _forward(q, k, v, spec: AttnSpec, q_offset, kv_len):
     """Returns (out (B,Sq,H,Dv), lse (B,Sq,KV,G))."""
     B, Sq, H, D = q.shape
@@ -108,19 +167,14 @@ def _forward(q, k, v, spec: AttnSpec, q_offset, kv_len):
             qblk, i = qi
             qpos = qpos_of(i)
 
-            def inner(c, kj):
-                kblk, vblk, j = kj
-                c2 = _block_update(c, qblk, kblk, vblk, qpos, kpos_of(j),
-                                   spec, kv_len)
-                if spec.causal:
-                    # skip fully-masked future blocks (cheap select)
-                    valid = (j * BK) <= (q_offset + i * BQ + BQ - 1)
-                    c2 = jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(valid, n, o), c2, c)
-                return c2, None
+            def inner(j, c):
+                kblk = jax.lax.dynamic_index_in_dim(kb, j, 0, False)
+                vblk = jax.lax.dynamic_index_in_dim(vb, j, 0, False)
+                return _block_update(c, qblk, kblk, vblk, qpos, kpos_of(j),
+                                     spec, kv_len)
 
-            (m, l, acc), _ = jax.lax.scan(inner, zinit(),
-                                          (kb, vb, jnp.arange(NK)))
+            lo, hi = _kv_range(i, spec, BQ, BK, NK, q_offset, kv_len)
+            m, l, acc = jax.lax.fori_loop(lo, hi + 1, inner, zinit())
             out = acc / jnp.maximum(l, 1e-30)[..., None]
             lse = m + jnp.log(jnp.maximum(l, 1e-30))
             return None, (out, lse)
@@ -191,6 +245,7 @@ def _recompute_p(q, k, lse, qpos, kpos, spec, kv_len):
     return p, t, mask
 
 
+@jax.named_scope(ATTN_SCOPE)
 def _backward(res, dout, spec: AttnSpec, q_offset, kv_len):
     q, k, v, out, lse = res
     B, Sq, H, D = q.shape
@@ -241,23 +296,21 @@ def _backward(res, dout, spec: AttnSpec, q_offset, kv_len):
             kblk, vblk, j = kj
             kpos = kpos_of(j)
 
-            def q_step(carry, qi):
-                dk, dv = carry
-                qblk, doblk, lseblk, dblk, i = qi
-                dkb, dvb, dqb = _block_bwd(qblk, kblk, vblk, doblk, lseblk,
-                                           dblk, qpos_of(i), kpos)
-                if spec.causal:
-                    valid = (j * BK) <= (q_offset + i * BQ + BQ - 1)
-                    dvb = jnp.where(valid, dvb, 0.0)
-                    dkb = jnp.where(valid, dkb, 0.0)
-                    dqb = jnp.where(valid, dqb, 0.0)
-                return (dk + dkb, dv + dvb), dqb
+            def q_step(i, carry):
+                dk, dv, dq_acc = carry
+                at = lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, False)
+                dkb, dvb, dqb = _block_bwd(at(qg), kblk, vblk, at(dog),
+                                           at(lseg), at(dg), qpos_of(i), kpos)
+                dq_acc = jax.lax.dynamic_update_index_in_dim(
+                    dq_acc, at(dq_acc) + dqb, i, 0)
+                return dk + dkb, dv + dvb, dq_acc
 
             zk = jnp.zeros((B, BK, KV, D), jnp.float32)
             zv = jnp.zeros((B, BK, KV, Dv), jnp.float32)
-            (dk, dv), dq_contrib = jax.lax.scan(
-                q_step, (zk, zv), (qg, dog, lseg, dg, jnp.arange(NQ)))
-            return dq_acc + dq_contrib, (dk, dv)
+            lo, hi = _q_range(j, spec, BQ, BK, NQ, q_offset, kv_len)
+            dk, dv, dq_acc = jax.lax.fori_loop(lo, hi + 1, q_step,
+                                               (zk, zv, dq_acc))
+            return dq_acc, (dk, dv)
 
         dq0 = jnp.zeros((NQ, B, BQ, KV, G, D), jnp.float32)
         dq_acc, (dks, dvs) = jax.lax.scan(kv_step, dq0,

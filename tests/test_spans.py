@@ -76,6 +76,31 @@ def test_step_scopes_split_every_matmul(tiny_step):
         assert re.search(r'op_name="jit\([a-z_]+\)/opt/', text)
 
 
+#: the attention core's einsums (``repro.models.attention_core``): scores
+#: (and the backward's dp), probabilities by values (and dq), and the
+#: backward's dk and dv
+CORE_EINSUMS = ("btkgd,bskd->btkgs", "btkgs,bskd->btkgd", "btkgs,btkgd->bskd")
+
+
+def test_attention_core_carries_its_scope_in_every_phase(tiny_step):
+    """Every matmul of the attention core carries an ``attn`` component in
+    its scope path, in the forward, the recompute and the backward, and
+    still falls in exactly one phase; no other matmul carries it."""
+    from repro.train.step import make_train_step
+    model, opt, params, opt_state, batch = tiny_step
+    fused = jax.jit(make_train_step(model, opt)).lower(
+        params, opt_state, batch).compile().as_text()
+    seen = set()
+    for name in _matmul_op_names(fused):
+        core = any(f"/{e}/" in name for e in CORE_EINSUMS)
+        assert ("attn" in name.split("/")) == core, name
+        if core:
+            got = _phases_of(name)
+            assert len(got) == 1, (name, got)
+            seen.add(got[0])
+    assert seen == {"fwd", "recompute", "bwd"}
+
+
 def test_cached_step_keeps_its_own_scopes(tmp_path):
     """With the program's compile cache, a step compiled under named scopes
     is not loaded from the entry of the same computation compiled without
